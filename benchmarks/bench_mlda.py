@@ -39,12 +39,8 @@ import numpy as np
 
 from repro.configs.tohoku_mlda import CPU, MLDAWorkloadConfig
 from repro.core import GaussianRandomWalk, MLDASampler, balanced_mlda
-from repro.swe import (
-    TohokuScenario,
-    make_hierarchy,
-    make_level_servers,
-    train_level0_gp,
-)
+from repro.swe import make_level_servers
+from repro.swe.inversion import build_inversion, level_densities
 
 # The CPU workload's grids (so the forward-solve cost spread is the real
 # one: fine ~70 ms >> coarse ~10 ms >> GP ~1 ms) with the GP training and
@@ -65,16 +61,9 @@ SMOKE = MLDAWorkloadConfig(
 
 
 def build(w: MLDAWorkloadConfig):
-    fine = TohokuScenario(nx=w.fine_grid[0], ny=w.fine_grid[1], t_end=w.t_end_s)
-    coarse = TohokuScenario(
-        nx=w.coarse_grid[0], ny=w.coarse_grid[1], t_end=w.t_end_s
-    )
-    h = make_hierarchy(fine=fine, coarse=coarse)
-    prob, f_fine, f_coarse = h["problem"], h["forward_fine"], h["forward_coarse"]
-    gp = train_level0_gp(
-        f_coarse, prob, n_train=w.gp_train_points, steps=w.gp_opt_steps
-    )
-    return prob, gp, f_coarse, f_fine
+    inv = build_inversion(w)
+    h = inv.hierarchy
+    return inv.problem, inv.gp, h["forward_coarse"], h["forward_fine"]
 
 
 def run_table1(w: MLDAWorkloadConfig, prob, gp, f_coarse, f_fine, n_fine: int):
@@ -151,19 +140,10 @@ def run_utilization(
 
 
 def _jax_densities(prob, gp, f_coarse):
-    """Traceable per-level log densities for the device kernel.
-
-    ``gp.__call__`` and the jitted coarse forward are both traceable, so
-    these compose straight into the fused vmapped chain step.  The third
-    return is a float-valued host twin of the surrogate density for the
-    step-machine baseline — same math, per-step Python dispatch.
-    """
-
-    def lp_gp(t):
-        return prob.log_prior_jax(t) + prob.log_likelihood_jax(gp(t))
-
-    def lp_coarse(t):
-        return prob.log_prior_jax(t) + prob.log_likelihood_jax(f_coarse(t))
+    """Traceable per-level log densities for the device kernel, plus a
+    float-valued host twin of the surrogate density for the step-machine
+    baseline — same math, per-step Python dispatch."""
+    lp_gp, lp_coarse = level_densities(prob, gp, f_coarse)
 
     def lp_gp_host(t):
         return float(lp_gp(jnp.asarray(np.asarray(t, np.float32))))

@@ -30,10 +30,9 @@ framed call; telemetry splits wire time from remote service time
 (``wire_split`` prints at the end).  ``--remote-json`` switches to the
 UM-Bridge HTTP/JSON interop mode for comparison.
 
-Run:  PYTHONPATH=src python examples/tsunami_inversion.py  (~5-10 min CPU)
+Run:  PYTHONPATH=src python examples/tsunami_inversion.py  (~5-10 min on a CPU)
 """
 import argparse
-import time
 from dataclasses import replace
 
 import jax
@@ -41,19 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.tohoku_mlda import CONFIGS
-from repro.core import (
-    GaussianRandomWalk,
-    available_policies,
-    balanced_mlda,
-)
+from repro.core import available_policies
 from repro.core.diagnostics import telescoping_estimate, variance_reduction_check
-from repro.swe import (
-    TohokuScenario,
-    make_hierarchy,
-    make_level_servers,
-    make_remote_level_servers,
-    train_level0_gp,
-)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.swe.inversion import build_inversion, sample_inversion
 
 
 def main():
@@ -78,6 +68,7 @@ def main():
         help="use the UM-Bridge HTTP/JSON interop mode instead of binary framing",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     w = CONFIGS[args.workload]
     if args.remote:
         endpoints = tuple(a.strip() for a in args.remote.split(",") if a.strip())
@@ -86,61 +77,27 @@ def main():
     policy = args.policy or w.balancer_policy
 
     print(f"[1/4] building {w.name} hierarchy "
-          f"(coarse {w.coarse_grid}, fine {w.fine_grid})")
-    fine = TohokuScenario(nx=w.fine_grid[0], ny=w.fine_grid[1], t_end=w.t_end_s)
-    coarse = TohokuScenario(nx=w.coarse_grid[0], ny=w.coarse_grid[1], t_end=w.t_end_s)
-    h = make_hierarchy(fine=fine, coarse=coarse)
-    prob, f_fine, f_coarse = h["problem"], h["forward_fine"], h["forward_coarse"]
+          f"(coarse {w.coarse_grid}, fine {w.fine_grid})"
+          + ("" if w.remote_servers else
+             f" + level-0 GP on {w.gp_train_points} LHS coarse solves"))
+    inv = build_inversion(w)
+    prob, coarse = inv.problem, inv.coarse
     print(f"      y_obs = {np.round(prob.y_obs, 4)} (truth at {prob.theta_true})")
-
     if w.remote_servers:
-        # The exporting processes own the level pools (GP included): no
-        # local surrogate training, just transports + remote replicas.
+        # The exporting processes own the level pools (GP included).
         print(f"[2/4] remote serving: dialing {list(w.remote_servers)} "
               f"({'binary' if w.remote_binary else 'UM-Bridge JSON'} mode)")
-        servers = make_remote_level_servers(w, w.remote_servers)
-        print(f"      {len(servers)} remote servers: "
-              f"{sorted(t for s in servers for t in s.capacity_tags)}")
     else:
-        print(f"[2/4] training level-0 GP on {w.gp_train_points} LHS coarse solves")
-        t0 = time.time()
-        gp = train_level0_gp(
-            f_coarse, prob, n_train=w.gp_train_points, steps=w.gp_opt_steps
-        )
-        print(f"      {time.time() - t0:.1f}s")
-        servers = make_level_servers(
-            w, gp, f_coarse, f_fine,
-            batch_forwards=(
-                None, h["forward_coarse_batch"], h["forward_fine_batch"]
-            ) if w.batch_solves else None,
-        )
+        print(f"[2/4] GP trained in {inv.gp_seconds:.1f}s")
 
     print(f"[3/4] MLDA x {n_chains} chains via the ensemble driver "
           f"(policy={policy}, speculative={w.speculative_prefetch}, "
           f"batch_solves={w.batch_solves})")
-
-    runner, lb = balanced_mlda(
-        servers,
-        prob.log_likelihood,
-        prob.log_prior,
-        GaussianRandomWalk(w.rw_step_km),
-        list(w.subchain_lengths),
-        policy=policy,
-        batchable_levels=w.batchable_levels,
-        n_chains=n_chains,
-        ensemble_seed=w.ensemble_seed,
-        speculative=w.speculative_prefetch,
-        as_runner=True,
-        **w.balancer_kwargs(),
-    )
-    t0 = time.time()
-    result = runner.run(
-        lambda c, rng: prob.sample_prior(rng)[0] * 0.5, w.n_fine_samples
-    )
-    wall = time.time() - t0
+    run = sample_inversion(inv, n_chains=n_chains, policy=policy)
+    result, s = run.result, run.summary
     samplers = result.samplers
 
-    print(f"[4/4] results ({wall:.0f}s sampling wall time)")
+    print(f"[4/4] results ({run.wall_s:.0f}s sampling wall time)")
     burn = max(2, w.n_fine_samples // 5)
     allc = result.pooled(burn)
     print(f"      fine posterior mean = {allc.mean(0).round(1)} km "
@@ -169,7 +126,6 @@ def main():
     print(f"      variance reduction up the hierarchy: "
           f"{variance_reduction_check(sample_sets)}")
 
-    s = lb.summary()
     print(f"      balancer idle (Fig. 9, policy={policy}): "
           f"mean={s['mean_idle_s'] * 1e3:.2f}ms "
           f"p99={s['p99_idle_s'] * 1e3:.1f}ms max={s['max_idle_s'] * 1e3:.1f}ms")
@@ -182,10 +138,6 @@ def main():
             print(f"        {key}: wire={wsp['wire_ewma_s'] * 1e3:.2f}ms "
                   f"service={wsp['service_ewma_s'] * 1e3:.2f}ms "
                   f"({wsp['calls']} calls)")
-    lb.shutdown()  # joins the dispatcher + worker pool; no leaked threads
-    if w.remote_servers:  # one shared transport per endpoint: close each once
-        for tr in {id(srv.transport): srv.transport for srv in servers}.values():
-            tr.close()
 
     # Fig. 6 analogue: GP over the full probe-0 time series.
     print("      fitting Fig. 6 time-series GP (probe 21418 analogue)")

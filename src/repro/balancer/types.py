@@ -248,14 +248,12 @@ class ShardedBatchServer(BatchServer):
         axes = self.policy.batch_axes(stacked.shape[0])
         if axes is None:
             return self.stacked_fn(stacked)
-        from repro.optim.grad_compression import shard_map  # portable wrapper
-
         def batch_spec(ndim: int) -> P:
             return P(axes, *([None] * (ndim - 1)))
 
         out_shape = jax.eval_shape(self.stacked_fn, stacked)
         out_specs = jax.tree.map(lambda s: batch_spec(len(s.shape)), out_shape)
-        return shard_map(
+        return jax.shard_map(
             self.stacked_fn,
             mesh=self.policy.mesh,
             in_specs=(batch_spec(stacked.ndim),),
@@ -274,6 +272,11 @@ class ShardedBatchServer(BatchServer):
             )
         out, n = self._aot(stacked)
         return jax.tree.map(lambda x: np.asarray(x)[:n], out)
+
+    @property
+    def executables(self) -> dict:
+        """The pool's compiled programs, keyed by ``(*cache_key, padded B)``."""
+        return {} if self._aot is None else self._aot.executables
 
 
 class DecodeHandoff(NamedTuple):

@@ -251,26 +251,6 @@ def _key_of(keydata: jax.Array) -> jax.Array:
     return jax.random.wrap_key_data(keydata, impl="threefry2x32")
 
 
-def _register_barrier_batching() -> None:
-    """``optimization_barrier`` has no vmap rule in this jax; it is
-    element-wise-transparent, so batching is dim-passthrough."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-
-        if optimization_barrier_p not in batching.primitive_batchers:
-
-            def _batch(args, dims):
-                return optimization_barrier_p.bind(*args), dims
-
-            batching.primitive_batchers[optimization_barrier_p] = _batch
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
-
-
-_register_barrier_batching()
-
-
 def _materialize(x: jax.Array) -> jax.Array:
     """Pin a sampled value to one bit pattern.
 

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import pallas_call
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -120,7 +122,6 @@ def flash_attention_pallas(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
 ) -> jax.Array:
     b, h, s, d = q.shape
     hkv = k.shape[1]
@@ -160,7 +161,7 @@ def flash_attention_pallas(
         block_k=bk,
         seq_len=s,
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(b * h, s_pad // bq, s_pad // bk),
         in_specs=[
@@ -175,6 +176,5 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s_pad, d)[:, :, :s, :]

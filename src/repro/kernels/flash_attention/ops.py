@@ -1,7 +1,6 @@
 """jit'd public wrapper for the flash-attention Pallas kernel."""
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
@@ -10,11 +9,9 @@ import jax
 from .flash_attention import flash_attention_pallas
 from .ref import attention_ref
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
 
 @partial(
-    jax.jit, static_argnames=("causal", "window", "scale", "impl", "interpret")
+    jax.jit, static_argnames=("causal", "window", "scale", "impl")
 )
 def attention(
     q: jax.Array,
@@ -25,12 +22,11 @@ def attention(
     window: Optional[int] = None,
     scale: Optional[float] = None,
     impl: str = "pallas",  # "pallas" | "xla"
-    interpret: bool = _INTERPRET,
 ) -> jax.Array:
     """Multi-head GQA attention: q (B,H,S,D), k/v (B,Hkv,S,D) -> (B,H,S,D).
 
     impl: "chunked" (portable flash-style scan, default for training cells),
-    "pallas" (TPU kernel / interpret mode), "xla" (naive — materialises the
+    "pallas" (compiled on TPU, interpreted elsewhere), "xla" (naive — materialises the
     (B,H,S,S) scores; oracle + tiny shapes only).
     """
     if impl == "xla":
@@ -41,5 +37,5 @@ def attention(
 
         return attention_chunked(q, k, v, causal=causal, window=window, scale=scale)
     return flash_attention_pallas(
-        q, k, v, causal=causal, window=window, scale=scale, interpret=interpret
+        q, k, v, causal=causal, window=window, scale=scale
     )

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_call
+
 SQRT5 = math.sqrt(5.0)
 
 DEFAULT_BLOCK_N = 128
@@ -34,11 +36,13 @@ def _matern52_kernel(s_ref, a_ref, b_ref, o_ref):
     outputscale = s_ref[0, 0]
     a = a_ref[...]  # (bn, d) VMEM tile
     b = b_ref[...]  # (bm, d) VMEM tile
-    # MXU: one matmul per tile; fp32 accumulation.
+    # MXU: one matmul per tile; fp32 accumulation.  HIGHEST, because the
+    # default single bf16 pass loses the distance to the cancellation below.
     ab = jax.lax.dot_general(
         a,
         b,
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     d2 = (
@@ -62,7 +66,6 @@ def matern52_pallas(
     *,
     block_n: int = DEFAULT_BLOCK_N,
     block_m: int = DEFAULT_BLOCK_M,
-    interpret: bool = True,
 ) -> jax.Array:
     """k(a, b) for pre-scaled a: (n, d), b: (m, d) -> (n, m)."""
     n, d = a.shape
@@ -76,7 +79,7 @@ def matern52_pallas(
     b_p = jnp.zeros((m_pad, d_pad), b.dtype).at[:m, :d].set(b)
     s = jnp.asarray(outputscale, a.dtype).reshape(1, 1)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         _matern52_kernel,
         grid=(n_pad // bn, m_pad // bm),
         in_specs=[
@@ -86,6 +89,5 @@ def matern52_pallas(
         ],
         out_specs=pl.BlockSpec((bn, bm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, m_pad), a.dtype),
-        interpret=interpret,
     )(s, a_p, b_p)
     return out[:n, :m]

@@ -1,23 +1,17 @@
 """jit'd public wrapper for the Matérn-5/2 Pallas kernel.
 
-On CPU (this container) the kernel executes in interpret mode; on TPU set
-``REPRO_PALLAS_COMPILE=1`` (or pass ``interpret=False``) to compile it.
+Compiled on a TPU, interpreted elsewhere (see :mod:`repro.kernels`).
 """
 from __future__ import annotations
-
-import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from .matern import matern52_pallas
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
-
-@partial(jax.jit, static_argnames=("interpret",))
-def matern52(x1: jax.Array, x2: jax.Array, params, *, interpret: bool = _INTERPRET):
+@jax.jit
+def matern52(x1: jax.Array, x2: jax.Array, params):
     """Drop-in replacement for :func:`repro.core.gp.matern52`.
 
     ``params`` is a :class:`repro.core.gp.GPParams`; ARD scaling happens here
@@ -26,6 +20,4 @@ def matern52(x1: jax.Array, x2: jax.Array, params, *, interpret: bool = _INTERPR
     ls = jnp.exp(params.log_lengthscales)
     a = x1 / ls
     b = x2 / ls
-    return matern52_pallas(
-        a, b, jnp.exp(params.log_outputscale), interpret=interpret
-    )
+    return matern52_pallas(a, b, jnp.exp(params.log_outputscale))

@@ -8,8 +8,6 @@ else through the batch-axis strip sweeps (DESIGN.md §7).
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -21,11 +19,9 @@ from .swe_flux import (
     swe_sweep_pallas,
 )
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
 
 def _strip_step(
-    state: SWEState, b: jax.Array, dt: float, cfg: SWEConfig, interpret: bool
+    state: SWEState, b: jax.Array, dt: float, cfg: SWEConfig
 ) -> SWEState:
     """One step via two strip sweeps; axis-generic over a leading batch dim.
 
@@ -43,12 +39,11 @@ def _strip_step(
     # x sweep
     dhx, dhux, dhvx = swe_sweep_pallas(
         padx(h), padx(hu), padx(hv), padx(b), g=cfg.g, dx=cfg.dx,
-        interpret=interpret,
     )
     # y sweep: transpose + swap (u, v)
     dhyT, dhvyT, dhuyT = swe_sweep_pallas(
         padx(swapT(h)), padx(swapT(hv)), padx(swapT(hu)), padx(swapT(b)),
-        g=cfg.g, dx=cfg.dy, interpret=interpret,
+        g=cfg.g, dx=cfg.dy,
     )
     dhy, dhuy, dhvy = swapT(dhyT), swapT(dhuyT), swapT(dhvyT)
 
@@ -67,10 +62,9 @@ def swe_step(
     dt: float,
     *,
     cfg: SWEConfig,
-    interpret: bool = _INTERPRET,
 ) -> SWEState:
     """Drop-in replacement for :func:`repro.swe.solver.step`."""
-    return _strip_step(state, b, dt, cfg, interpret)
+    return _strip_step(state, b, dt, cfg)
 
 
 def _fused_fits(cfg: SWEConfig, itemsize: int = 4) -> bool:
@@ -84,7 +78,6 @@ def swe_step_batched(
     *,
     cfg: SWEConfig,
     fused: bool = True,
-    interpret: bool = _INTERPRET,
 ) -> SWEState:
     """One time step for a stacked batch: state arrays are ``(B, ny, nx)``.
 
@@ -99,10 +92,10 @@ def swe_step_batched(
         b2 = jnp.pad(b, ((1, 1), (1, 1)), mode="edge")
         h_new, hu_new, hv_new = swe_fused_step_pallas(
             padb(h), padb(hu), padb(hv), b2,
-            g=cfg.g, dx=cfg.dx, dy=cfg.dy, dt=dt, interpret=interpret,
+            g=cfg.g, dx=cfg.dx, dy=cfg.dy, dt=dt,
         )
         return SWEState(h_new, hu_new, hv_new)
     # strip sweeps with the batch grid axis (same body as swe_step)
     return _strip_step(
-        state, jnp.broadcast_to(b[None], h.shape), dt, cfg, interpret
+        state, jnp.broadcast_to(b[None], h.shape), dt, cfg
     )

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_call
+
 H_EPS = 1e-3
 DEFAULT_BLOCK_ROWS = 8
 # Conservative per-member VMEM budget for the fused kernel: 7 fp32 planes
@@ -114,7 +116,6 @@ def swe_sweep_pallas(
     g: float,
     dx: float,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
 ):
     """Directional flux sweep; with 3D inputs the grid gains a batch axis.
 
@@ -144,13 +145,12 @@ def swe_sweep_pallas(
         in_spec = pl.BlockSpec((br, nxp), lambda i: (i, 0))
         out_spec = pl.BlockSpec((br, nxp - 2), lambda i: (i, 0))
         out_shape = [jax.ShapeDtypeStruct((ny_pad, nxp - 2), h.dtype)] * 3
-    dh, dhu, dhv = pl.pallas_call(
+    dh, dhu, dhv = pallas_call(
         kernel,
         grid=grid,
         in_specs=[in_spec] * 4,
         out_specs=[out_spec] * 3,
         out_shape=out_shape,
-        interpret=interpret,
     )(h, hu, hv, b)
     if batched:
         return dh[:, :ny], dhu[:, :ny], dhv[:, :ny]
@@ -211,7 +211,6 @@ def swe_fused_step_pallas(
     dx: float,
     dy: float,
     dt: float,
-    interpret: bool = True,
 ):
     """One fused time step for a stacked batch: grid ``(B,)``, one launch.
 
@@ -232,11 +231,10 @@ def swe_fused_step_pallas(
     )
     in_spec = pl.BlockSpec((1, nyp, nxp), lambda n: (n, 0, 0))
     out_spec = pl.BlockSpec((1, nyp - 2, nxp - 2), lambda n: (n, 0, 0))
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(B,),
         in_specs=[in_spec] * 4,
         out_specs=[out_spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((B, nyp - 2, nxp - 2), h.dtype)] * 3,
-        interpret=interpret,
     )(h, hu, hv, bb)

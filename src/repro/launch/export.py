@@ -2,8 +2,9 @@
 
 The server half of the two-process deployment the paper runs (simulation
 servers behind UM-Bridge, balancer in the sampling process): build the
-workload's hierarchy + GP surrogate exactly like
-``examples/tsunami_inversion.py`` does, wrap the resulting pool in a
+workload's hierarchy + GP surrogate with the same
+:func:`repro.swe.inversion.build_inversion` call as
+``examples/tsunami_inversion.py``, wrap the resulting pool in a
 :class:`~repro.net.server.ServerShell`, and serve until interrupted.
 Both protocols share the port — this process is a valid UM-Bridge model
 server (``GET /Info`` / ``POST /Evaluate``) *and* the binary-framing
@@ -38,28 +39,11 @@ def build_shell(w, *, host: str, port: int, levels: str = "all"):
     """
     # Imports deferred: --help must not pay jax startup.
     from repro.net import ServerShell
-    from repro.swe import (
-        TohokuScenario,
-        make_hierarchy,
-        make_level_servers,
-        train_level0_gp,
-    )
+    from repro.swe.inversion import build_inversion
 
-    fine = TohokuScenario(nx=w.fine_grid[0], ny=w.fine_grid[1], t_end=w.t_end_s)
-    coarse = TohokuScenario(
-        nx=w.coarse_grid[0], ny=w.coarse_grid[1], t_end=w.t_end_s
-    )
-    h = make_hierarchy(fine=fine, coarse=coarse)
-    prob, f_fine, f_coarse = h["problem"], h["forward_fine"], h["forward_coarse"]
-    gp = train_level0_gp(
-        f_coarse, prob, n_train=w.gp_train_points, steps=w.gp_opt_steps
-    )
-    servers = make_level_servers(
-        w, gp, f_coarse, f_fine,
-        batch_forwards=(
-            None, h["forward_coarse_batch"], h["forward_fine_batch"]
-        ) if w.batch_solves else None,
-    )
+    inv = build_inversion(w)
+    prob = inv.problem
+    servers = inv.level_servers()
     if levels != "all":
         keep = {f"level{int(x)}" for x in levels.split(",")}
         servers = [
@@ -93,8 +77,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     from repro.configs.tohoku_mlda import CONFIGS
+    from repro.launch.compile_cache import enable_compile_cache
 
     w = CONFIGS[args.workload]
+    enable_compile_cache()
     print(f"[export] building {w.name} hierarchy + GP "
           f"(coarse {w.coarse_grid}, fine {w.fine_grid}) ...")
     t0 = time.time()

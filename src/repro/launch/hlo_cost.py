@@ -45,15 +45,8 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all", "col
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Version-portable ``compiled.cost_analysis()``.
-
-    jax 0.4.x returns a one-element list of dicts (one per program), newer
-    jax returns the dict itself; normalise to the dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """XLA's own cost estimate of a compiled program (flops, bytes accessed)."""
+    return compiled.cost_analysis()
 
 
 def _shape_bytes(type_str: str) -> int:

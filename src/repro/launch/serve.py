@@ -1,6 +1,10 @@
 """Serving driver: continuous-batching LM serving through the load balancer.
 
-``python -m repro.launch.serve --arch qwen2-0.5b --reduced --requests 32``
+``python -m repro.launch.serve --arch qwen2-0.5b --requests 32``
+
+Models run at their published widths on an accelerator and as
+``.reduced()`` variants on the CPU; ``--reduced`` / ``--no-reduced``
+overrides that default.
 
 The dispatcher is the paper's contribution re-used at the LM layer
 (DESIGN.md §10): prefill and decode are disaggregated into two balancer
@@ -21,9 +25,11 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.serve_loop import ServingEngine, serving_metrics
 
 
@@ -35,7 +41,12 @@ def main() -> None:
         default=None,
         help="model variant(s); repeat for a heterogeneous pool",
     )
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument(
+        "--reduced",
+        action=argparse.BooleanOptionalAction,
+        default=None,
+        help="serve .reduced() variants (default: only on the CPU)",
+    )
     ap.add_argument(
         "--mode",
         choices=["continuous", "generation", "paged", "speculative"],
@@ -65,11 +76,13 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = args.arch or ["qwen2-0.5b"]
-    variants = {
-        n: (ARCHS[n].reduced() if args.reduced else ARCHS[n]) for n in names
-    }
+    reduced = args.reduced
+    if reduced is None:
+        reduced = jax.default_backend() == "cpu"
+    variants = {n: (ARCHS[n].reduced() if reduced else ARCHS[n]) for n in names}
 
     if args.mode == "continuous" and args.kv == "paged":
         args.mode = "paged"  # same normalization the engine applies

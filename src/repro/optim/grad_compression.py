@@ -25,23 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x keeps it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(_inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-portable ``shard_map``: drops kwargs the installed jax lacks
-    and maps ``check_vma`` (new name) onto ``check_rep`` (old name)."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    kwargs = {k: v for k, v in kwargs.items() if k in _SHARD_MAP_PARAMS}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
 
 def quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Symmetric per-tensor int8 quantisation; returns (q, scale)."""
@@ -99,7 +82,7 @@ def make_compressed_dp_grad_fn(loss_fn, mesh: Mesh, axis_name: str = "data"):
         return jax.tree.map(lambda _: spec, tree)
 
     def grad_fn(params, err, batch):
-        fn = shard_map(
+        fn = jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(spec_like(params, replicated), spec_like(err, replicated),

@@ -26,7 +26,9 @@ from repro.runtime.train_loop import TrainRuntime, shard_train_step
 from repro.runtime.serve_loop import shard_decode_step
 
 arch_id = sys_argv_arch
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh(
+    (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+)
 cfg = ARCHS[arch_id].reduced()
 out = {}
 

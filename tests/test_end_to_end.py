@@ -115,3 +115,39 @@ def test_variance_reduction_and_balancer_idle(hierarchy):
     assert s["n_requests"] > 50
     # mean idle time is small relative to a coarse solve (paper Fig. 9)
     assert s["mean_idle_s"] < 0.25
+
+
+@pytest.mark.parametrize("device_resident", [False, True], ids=["step_machines", "device_resident"])
+def test_inversion_build_and_sample(device_resident):
+    """The shared build/sample calls the example, export and chip smoke use."""
+    import dataclasses
+
+    from repro.configs.tohoku_mlda import CPU
+    from repro.swe.inversion import build_inversion, sample_inversion
+
+    w = dataclasses.replace(
+        CPU, coarse_grid=(16, 16), fine_grid=(24, 24), t_end_s=1800.0,
+        gp_train_points=24, gp_opt_steps=10, device_resident=device_resident,
+    )
+    inv = build_inversion(w)
+    assert inv.gp is not None and inv.gp.x_train.shape[0] == 24
+    run = sample_inversion(inv, n_chains=3, policy="fifo", n_fine_samples=4)
+    assert run.result.chains.shape == (3, 4, 2)
+    assert not run.result.failures and run.summary["failures"] == 0
+    assert np.all(np.isfinite(run.result.chains))
+    assert run.leaked_threads <= 0
+    assert run.result.level_totals()[-1]["n_evals"] > 0
+    # Device-resident chains send only fine solves through the balancer.
+    assert (run.summary["batch_histogram"].keys() <= {"level2"}) == device_resident
+
+
+def test_device_resident_rejects_remote_pools():
+    import dataclasses
+
+    from repro.configs.tohoku_mlda import CPU
+    from repro.swe.inversion import Inversion, sample_inversion
+
+    w = dataclasses.replace(CPU, device_resident=True, remote_servers=("127.0.0.1:1",))
+    inv = Inversion(w, None, None, {"problem": None}, None)
+    with pytest.raises(ValueError, match="remote"):
+        sample_inversion(inv, n_chains=2, policy="fifo")
