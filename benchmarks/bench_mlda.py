@@ -199,7 +199,6 @@ def run_utilization_device(
         "busy_s": busy,
         "utilization": util,
         "n_requests": summary["n_requests"],
-        "device_seconds": runner.device_seconds,
         "fine_evals": totals[-1]["n_evals"],
     }
 
@@ -315,7 +314,6 @@ def main(smoke: bool = False, n_fine: int = 0, ensemble_chains: int = 0):
     rows.append(
         f"mlda_pool_util_device,{device['utilization']:.3f},frac"
     )
-    rows.append(f"mlda_device_seconds,{device['device_seconds']:.3f},s")
     rows.append(f"mlda_spec_hits,{multi['n_spec_hits']},count")
     rows.append(f"mlda_spec_attempts,{multi['n_speculated']},count")
 

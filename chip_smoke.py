@@ -342,7 +342,6 @@ def phase_uq_mlda(jax, inv, n_chains=N_CHAINS, n_fine=N_FINE_SAMPLES):
         Fact("requests", s["n_requests"]),
         Fact("mean_idle_ms", round(s["mean_idle_s"] * 1e3, 3)),
         Fact("sampling_wall_s", round(run.wall_s, 2)),
-        Fact("device_s", round(run.device_seconds, 2)),
         Fact("batch_histogram", s["batch_histogram"]),
     ]
 

@@ -34,6 +34,12 @@ byte-identical ``fifo`` dispatch order vs the recorded seed trace):
   is queued (see ``_execute_batched``), instead of unconditionally
   sleeping a pool slot.
 
+Each request's queue delay is stamped in three parts — decision, worker
+pickup, dispatch (``Request.wait_split``, summed per tag in
+``summary()['wait_split']``) — and the coalescing wait and every server
+call carry profiler spans (``repro.dispatch.coalesce``,
+``repro.dispatch.serve``) on the device trace's clock.
+
 The paper's design points survive intact: one persistent pool for the
 whole run, FIFO arrival order under a mutex, event-driven wakeup via
 condition variables (no polling), zero assumptions about task runtimes.
@@ -46,6 +52,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from .health import HealthConfig, HealthMonitor
 from .policies import PolicyContext, SchedulingPolicy, create_policy
@@ -588,6 +596,7 @@ class LoadBalancer:
                 return pairs
             req, server = pair
             self._queue.pop(req)  # O(1): req is its tag's head
+            req.decided_at = time.monotonic()
             server.busy = True  # server.markBusy()
             self._free.mark_busy(server)
             pairs.append(pair)
@@ -732,23 +741,27 @@ class LoadBalancer:
     def _execute(
         self, req: Request, server: Server
     ) -> Optional[Tuple[Request, Server]]:
-        req.dispatched_at = time.monotonic()
+        req.picked_at = req.dispatched_at = time.monotonic()
         req.server = server.name
         if server.continuous:
             return self._execute_continuous(req, server)
         if req.batchable and server.batch_fn is not None and self.batch_window_s > 0:
             return self._execute_batched(req, server)
         try:
-            if server.batch_fn is not None:
-                # Batch-capable servers evaluate through batch_call even for
-                # a lone request, so the per-member error channel (Exception
-                # results, check_finite) has the same semantics whether or
-                # not the request was coalesced: the member fails alone, the
-                # server survives.  Routing through _single/fn instead would
-                # re-raise the member error here and kill the server below.
-                result = server.batch_call([req.theta])[0]
-            else:
-                result = server.fn(req.theta)  # return server(request[j])
+            with TraceAnnotation(
+                "repro.dispatch.serve", tag=req.tag, req=req.seq, rows=1
+            ):
+                if server.batch_fn is not None:
+                    # Batch-capable servers evaluate through batch_call even
+                    # for a lone request, so the per-member error channel
+                    # (Exception results, check_finite) has the same
+                    # semantics whether or not the request was coalesced:
+                    # the member fails alone, the server survives.  Routing
+                    # through _single/fn instead would re-raise the member
+                    # error here and kill the server below.
+                    result = server.batch_call([req.theta])[0]
+                else:
+                    result = server.fn(req.theta)  # return server(request[j])
         except Exception:  # noqa: BLE001 - any worker fault kills the server
             self._fail_dispatch(req, server)
             return None
@@ -805,6 +818,7 @@ class LoadBalancer:
                 if pair is not None:
                     nreq, nserver = pair
                     self._queue.pop(nreq)
+                    nreq.decided_at = time.monotonic()
                     nserver.busy = True
                     self._free.mark_busy(nserver)
                     return pair
@@ -935,7 +949,8 @@ class LoadBalancer:
                         waiter = _BatchWaiter(needed=limit - 1)
                         self._batch_waiters.setdefault(req.tag, []).append(waiter)
         if waiter is not None:
-            waiter.event.wait(window)  # early-fired by the submit path
+            with TraceAnnotation("repro.dispatch.coalesce", tag=req.tag, req=req.seq):
+                waiter.event.wait(window)  # early-fired by the submit path
             with self._cv:
                 waiters = self._batch_waiters.get(req.tag)
                 if waiters is not None:
@@ -956,8 +971,13 @@ class LoadBalancer:
         for r in members:
             r.dispatched_at = now
             r.server = server.name
+        for r in extra:  # decided by the drain, picked with the primary
+            r.decided_at = r.picked_at = now
         try:
-            results = server.batch_call([r.theta for r in members])
+            with TraceAnnotation(
+                "repro.dispatch.serve", tag=req.tag, req=req.seq, rows=len(members)
+            ):
+                results = server.batch_call([r.theta for r in members])
         except Exception:  # noqa: BLE001 - whole-call fault kills the server
             # Coalesced members retry elsewhere — each burns one retry (and
             # one distinct-server kill toward the poison threshold), so
@@ -1104,7 +1124,7 @@ class LoadBalancer:
                     return
                 self._queue.pop(head)
             now = time.monotonic()
-            head.dispatched_at = now
+            head.decided_at = head.picked_at = head.dispatched_at = now
             head.server = server.name
             done = self._admit_one(head, server, now)
             if done is not None:

@@ -20,6 +20,8 @@ memory is bounded for million-request runs —
 * idle-time statistics are running moments (count / sum / max) plus
   :class:`P2Quantile` estimators (Jain & Chlamtac's P² algorithm) for the
   p50/p99 the paper's Fig. 9 reports — no sort over the full history;
+  their sums are also kept split three ways per tag
+  (``summary()['wait_split']``: decision wait, hand-off, coalescing);
 * ``runtime_quantile`` answers from a bounded per-tag window of recent
   service times (sorted on read, O(window log window)), instead of
   sorting every runtime ever recorded on each hedged submit.
@@ -170,6 +172,12 @@ class Telemetry:
         self._idle_max = 0.0
         self._idle_p50 = P2Quantile(0.50)
         self._idle_p99 = P2Quantile(0.99)
+        # the idle moments' sums split three ways (Request.wait_split), per
+        # tag and over all tags ("*"): [n, dispatch_wait, handoff, coalesce]
+        self._split: Dict[str, List[float]] = {"*": [0, 0.0, 0.0, 0.0]}
+        # client side: completion to the moment the client resumed on it
+        self._resume_n = 0
+        self._resume_s = 0.0
 
     @property
     def exact(self) -> bool:
@@ -259,6 +267,9 @@ class Telemetry:
                 )
             elif kind == "fault":
                 self._faults[(a, b)] = self._faults.get((a, b), 0) + 1
+            elif kind == "resume":
+                self._resume_n += 1
+                self._resume_s += a
             elif kind == "occupancy":
                 occupied, capacity = b
                 occ = self._occupancy.get(a)
@@ -315,6 +326,17 @@ class Telemetry:
             self._idle_max = delay
         self._idle_p50.add(delay)
         self._idle_p99.add(delay)
+        self._add_split_locked(req, 1)
+
+    def _add_split_locked(self, req: Request, sign: int) -> None:
+        parts = req.wait_split()
+        row = self._split.get(req.tag)
+        if row is None:
+            row = self._split[req.tag] = [0, 0.0, 0.0, 0.0]
+        for acc in (row, self._split["*"]):
+            acc[0] += sign
+            for i, x in enumerate(parts, 1):
+                acc[i] += sign * x
 
     def rebook_hedged(self, winner: Request, loser: Request) -> None:
         """Repair idle aggregates after a hedge race resolves.
@@ -331,6 +353,7 @@ class Telemetry:
                 loser.idle_booked = False
                 self._idle_n -= 1
                 self._idle_sum -= loser.queue_delay
+                self._add_split_locked(loser, -1)
             if winner.done.is_set():
                 self._book_idle_locked(winner)
 
@@ -389,6 +412,13 @@ class Telemetry:
         bottleneck of a distributed run.
         """
         self._pending.append(("wire", (server, tag), (wire_s, service_s)))
+        self._maybe_fold()
+
+    def record_resume(self, seconds: float) -> None:
+        """Book one client resumption: ``seconds`` from a request's
+        completion to the moment its client started on the result.
+        Surfaced as ``summary()['resume']``."""
+        self._pending.append(("resume", seconds, None))
         self._maybe_fold()
 
     def record_fault(self, kind: str, tag: str = "") -> None:
@@ -554,6 +584,16 @@ class Telemetry:
             for (kind, tag), n in self._faults.items():
                 fault_counters.setdefault(kind, {})[tag] = n
             stats["fault_counters"] = fault_counters
+            stats["wait_split"] = {
+                tag: {
+                    "n": int(row[0]),
+                    "dispatch_wait_s": row[1],
+                    "handoff_s": row[2],
+                    "coalesce_s": row[3],
+                }
+                for tag, row in self._split.items()
+            }
+            stats["resume"] = {"n": self._resume_n, "sum_s": self._resume_s}
             stats["slot_occupancy"] = {
                 name: {
                     "mean": occ["slot_steps"] / (occ["steps"] * occ["capacity"])

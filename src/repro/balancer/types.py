@@ -773,6 +773,11 @@ class Request:        # numpy thetas ("truth value ambiguous" in queue.remove)
     tag: str = ""
     batchable: bool = False
     arrived_at: float = 0.0
+    # popped from the queue by a dispatch decision (for a member drained
+    # into another request's batch: the drain)
+    decided_at: float = 0.0
+    # a worker started on it (for a drained batch member: its dispatch)
+    picked_at: float = 0.0
     dispatched_at: float = 0.0
     completed_at: float = 0.0
     server: Optional[str] = None
@@ -805,6 +810,20 @@ class Request:        # numpy thetas ("truth value ambiguous" in queue.remove)
     def queue_delay(self) -> float:
         """Time between arrival and dispatch — the paper's 'idle time'."""
         return self.dispatched_at - self.arrived_at
+
+    def wait_split(self) -> Tuple[float, float, float]:
+        """``queue_delay`` in three parts that add up to it: the wait for a
+        dispatch decision, the hand-off from the decision to a worker, and
+        the coalescing window the worker held it in.  A request that never
+        passed a dispatch decision (built by hand) books it all as the
+        first part."""
+        decided = self.decided_at or self.dispatched_at
+        picked = self.picked_at or self.dispatched_at
+        return (
+            decided - self.arrived_at,
+            picked - decided,
+            self.dispatched_at - picked,
+        )
 
     def cancel(self) -> bool:
         """Cancel this request if it is still *queued* (client-side

@@ -8,6 +8,13 @@ remote evaluation, submits those evaluations through the shared balancer
 (``submit_async`` via :meth:`BalancedDensity.begin`), and sleeps in
 :func:`repro.balancer.futures.wait_any` until any of them completes —
 event-driven fan-in, no polling, no per-chain threads.
+
+The runner's own time is visible on the profiler's clock: each step of a
+chain's Python machine runs in a ``repro.runner.step`` span (stats
+``chain`` and ``req``, the request it resumed on, -1 if none) and each
+sleep in a ``repro.runner.wait`` span; the time from a request's
+completion to the runner's resumption on it is booked into the
+balancer's telemetry (``summary()['resume']``).
 """
 from __future__ import annotations
 
@@ -18,6 +25,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.balancer import LoadBalancer
 from repro.core.diagnostics import effective_sample_size, gelman_rubin
@@ -206,12 +214,17 @@ class EnsembleRunner:
         # per wait round), so long-running solves don't accumulate stale
         # callbacks while other chains' requests churn.
         wake = threading.Event()
+        # chain index -> seq of the request it was just resumed on
+        resumed: Dict[int, int] = {}
         printed = 0
         while runnable or parked:
             revived: List[int] = []
             for c in runnable:
                 try:
-                    wait = self._pump(c, chains[c], inflight[c])
+                    with TraceAnnotation(
+                        "repro.runner.step", chain=c, req=resumed.pop(c, -1)
+                    ):
+                        wait = self._pump(c, chains[c], inflight[c])
                 except Exception as e:  # noqa: BLE001 - isolate this chain
                     if self._resume(
                         c, e, chains, inflight, prefix, snapshots,
@@ -240,14 +253,19 @@ class EnsembleRunner:
             if not parked:
                 break  # every chain finished (or failed)
             if not any(req.done.is_set() for (_pe, _lp, req) in parked.values()):
-                wake.wait()
+                with TraceAnnotation("repro.runner.wait"):
+                    wake.wait()
             wake.clear()
             for c in list(parked):
                 pe, lp, req = parked[c]
                 if req.done.is_set():
                     del parked[c]
+                    resumed[c] = req.seq
                     try:
-                        self._finish(chains[c].sampler, pe, lp, req)
+                        with TraceAnnotation(
+                            "repro.runner.step", chain=c, req=req.seq
+                        ):
+                            self._finish(chains[c].sampler, pe, lp, req)
                     except Exception as e:  # noqa: BLE001
                         if self._resume(
                             c, e, chains, inflight, prefix, snapshots,
@@ -444,8 +462,13 @@ class EnsembleRunner:
         v = float(density(pe.theta))
         pe.resolve(v, seconds=time.monotonic() - t0)
 
-    @staticmethod
-    def _finish(sampler: MLDASampler, pe: PendingEval, lp: float, req: Any) -> None:
+    def _finish(
+        self, sampler: MLDASampler, pe: PendingEval, lp: float, req: Any
+    ) -> None:
+        if self.balancer is not None and req.error is None:
+            self.balancer.telemetry.record_resume(
+                time.monotonic() - req.completed_at
+            )
         density = sampler.log_posteriors[pe.level]
         v = density.finish(lp, req)  # raises if the request errored
         pe.resolve(v, seconds=req.service_time)
@@ -524,7 +547,6 @@ class DeviceEnsembleRunner:
         self.seed = int(seed)
         self.chunk = max(int(chunk), 1)
         self.balancer = balancer or getattr(fine_density, "balancer", None)
-        self.device_seconds = 0.0  # wall-clock inside fused device launches
         self.state = None  # EnsembleState after run()
 
     # -- driver ---------------------------------------------------------------
@@ -582,11 +604,8 @@ class DeviceEnsembleRunner:
         printed = 0
         while drawn < n_samples:
             k = min(self.chunk, n_samples - drawn)
-            t0 = time.monotonic()
             state, thetas, _logps = ens.advance(state, k)
-            block = np.asarray(thetas)  # host sync: launch really finished
-            self.device_seconds += time.monotonic() - t0
-            out.append(block)
+            out.append(np.asarray(thetas))
             drawn += k
             if progress_every:
                 total = drawn * theta0.shape[0]
@@ -618,11 +637,9 @@ class DeviceEnsembleRunner:
         printed = 0
         asynchronous = hasattr(density, "begin")
         for i in range(n_samples):
-            t0 = time.monotonic()
             state, pending = ens.propose(state)
             moved = np.asarray(pending.moved)
             psi = np.asarray(pending.psi)
-            self.device_seconds += time.monotonic() - t0
             logp_psi = np.zeros(n_chains, np.float32)
             inflight: Dict[int, Tuple[float, Any]] = {}
             for c in np.nonzero(moved)[0]:
@@ -641,10 +658,8 @@ class DeviceEnsembleRunner:
                 # stacked batches; finishing in order just collects results.
                 logp_psi[c] = density.finish(lp, req)
                 top_seconds[c] += req.service_time
-            t2 = time.monotonic()
             state, _accepted = ens.accept(state, pending, logp_psi)
             samples[:, i] = np.asarray(state.theta)
-            self.device_seconds += time.monotonic() - t2
             if progress_every:
                 total = (i + 1) * n_chains
                 while total >= printed + progress_every:

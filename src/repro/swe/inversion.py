@@ -95,7 +95,6 @@ class InversionRun:
     result: Any  # repro.ensemble.EnsembleResult
     summary: Dict[str, Any]
     wall_s: float
-    device_seconds: float  # fused device launches (device-resident mode)
     leaked_threads: int  # threads still alive after the balancer shut down
 
 
@@ -169,6 +168,5 @@ def sample_inversion(
         result=result,
         summary=summary,
         wall_s=wall,
-        device_seconds=getattr(runner, "device_seconds", 0.0),
         leaked_threads=threading.active_count() - threads_before,
     )

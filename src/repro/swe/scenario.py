@@ -186,6 +186,7 @@ class TohokuScenario:
         cache = AOTBatchCache(
             jax.vmap(single), key=(self.ny, self.nx),
             dtype=jnp.result_type(float), pad="repeat",
+            name=f"swe_forward_{self.ny}x{self.nx}",
         )
 
         def forward(thetas: jax.Array) -> jax.Array:

@@ -186,6 +186,10 @@ class AOTBatchCache:
     their leading axis (e.g. a device-resident ensemble state) —
     ``dtype=None`` then preserves each leaf's own dtype instead of casting
     (RNG keys stay uint32, counters stay int32).
+
+    ``name``, when given, names the compiled programs (``jit_<name>``), so
+    a profiler trace tells them apart from other programs built from a
+    function of the same name.
     """
 
     def __init__(
@@ -196,9 +200,17 @@ class AOTBatchCache:
         dtype=None,
         donate: bool = False,
         pad: str = "zeros",
+        name: Optional[str] = None,
     ) -> None:
         if pad not in ("zeros", "repeat"):
             raise ValueError(f"unknown pad mode '{pad}'")
+        if name is not None:
+            fn = stacked_fn
+
+            def stacked_fn(stacked):
+                return fn(stacked)
+
+            stacked_fn.__name__ = stacked_fn.__qualname__ = name
         self.stacked_fn = stacked_fn
         self.key = tuple(key)
         self.dtype = dtype
