@@ -13,6 +13,11 @@ time-series reconstruction of Fig. 6.
 The O(n^2 d) kernel-matrix assembly is the compute hot-spot; a Pallas TPU
 kernel lives in ``repro.kernels.matern`` (used when ``use_pallas=True``),
 with this module's pure-jnp path as the reference implementation.
+
+The posterior mean, which level 0 of the MLDA hierarchy serves, is one
+compiled program per batch size (:func:`posterior_mean`): the trained
+state is passed as jit arguments, so every GP fitted with the same shapes
+shares those programs.
 """
 from __future__ import annotations
 
@@ -33,23 +38,57 @@ class GPParams(NamedTuple):
     log_noise: jax.Array  # ()
 
 
+def _matern52_of_sq_dist(d2: jax.Array, params: GPParams) -> jax.Array:
+    """Matérn-5/2 of squared lengthscale-scaled distances."""
+    # The double-where keeps the gradient of sqrt finite at d2 == 0 (the
+    # diagonal), else ML-II training NaNs out.
+    d2 = jnp.maximum(d2, 0.0)
+    safe = jnp.where(d2 > 1e-24, d2, 1.0)
+    d = jnp.where(d2 > 1e-24, jnp.sqrt(safe), 0.0)
+    s = SQRT5 * d
+    return jnp.exp(params.log_outputscale) * (1.0 + s + s * s / 3.0) * jnp.exp(-s)
+
+
 def matern52(x1: jax.Array, x2: jax.Array, params: GPParams) -> jax.Array:
     """Matérn-5/2 ARD kernel matrix k(x1, x2): (n, d) x (m, d) -> (n, m)."""
     ls = jnp.exp(params.log_lengthscales)
     a = x1 / ls
     b = x2 / ls
-    # Pairwise Euclidean distances.  The double-where keeps the gradient of
-    # sqrt finite at d2 == 0 (the diagonal), else ML-II training NaNs out.
-    # HIGHEST: a TPU runs fp32 matmuls as one bf16 pass by default, and
-    # |a|^2 + |b|^2 - 2ab cancels — 3e-2 error in k on a v5e without it.
+    # Pairwise Euclidean distances.  HIGHEST: a TPU runs fp32 matmuls as
+    # one bf16 pass by default, and |a|^2 + |b|^2 - 2ab cancels — 3e-2
+    # error in k on a v5e without it.
     ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
     d2 = jnp.sum(a * a, -1)[:, None] + jnp.sum(b * b, -1)[None, :] - 2.0 * ab
-    d2 = jnp.maximum(d2, 0.0)
-    safe = jnp.where(d2 > 1e-24, d2, 1.0)
-    d = jnp.where(d2 > 1e-24, jnp.sqrt(safe), 0.0)
-    s = SQRT5 * d
-    out = jnp.exp(params.log_outputscale) * (1.0 + s + s * s / 3.0) * jnp.exp(-s)
+    return _matern52_of_sq_dist(d2, params)
+
+
+def _dot_last(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``sum_k a[..., k] * b[..., k]``, added in the order k = 0, 1, ...
+
+    The order is fixed by the code, not picked by the compiler per shape
+    as it is for a reduce or a matmul.
+    """
+    out = a[..., 0] * b[..., 0]
+    for k in range(1, a.shape[-1]):
+        out = out + a[..., k] * b[..., k]
     return out
+
+
+def _matern52_rows(x1: jax.Array, x2: jax.Array, params: GPParams) -> jax.Array:
+    """:func:`matern52` with each row's arithmetic independent of how many
+    rows ``x1`` has.
+
+    The distances are float32 products and sums on the vector unit, not a
+    matmul: a v5e runs the ``HIGHEST`` matmul on the MXU for two rows or
+    more but rewrites it into this float32 form for one, so the rows of
+    the two differ by ulps.
+    """
+    ls = jnp.exp(params.log_lengthscales)
+    a = x1 / ls
+    b = x2 / ls
+    ab = _dot_last(a[:, None, :], b[None, :, :])
+    d2 = _dot_last(a, a)[:, None] + _dot_last(b, b)[None, :] - 2.0 * ab
+    return _matern52_of_sq_dist(d2, params)
 
 
 def _kernel_fn(use_pallas: bool) -> Callable:
@@ -58,6 +97,52 @@ def _kernel_fn(use_pallas: bool) -> Callable:
 
         return matern_ops.matern52
     return matern52
+
+
+def _tree_sum(t: jax.Array) -> jax.Array:
+    """Sum of (m, n, p) over n as a fixed binary tree of elementwise adds.
+
+    n is zero-padded to a power of two; adding a zero is exact.
+    """
+    n = t.shape[1]
+    t = jnp.pad(t, ((0, 0), (0, (1 << (n - 1).bit_length()) - n), (0, 0)))
+    while t.shape[1] > 1:
+        half = t.shape[1] // 2
+        t = t[:, :half] + t[:, half:]
+    return t[:, 0]
+
+
+@partial(jax.jit, static_argnames=("use_pallas",))
+def posterior_mean(
+    x: jax.Array,
+    x_train: jax.Array,
+    alpha: jax.Array,
+    y_scale: jax.Array,
+    y_mean: jax.Array,
+    params: GPParams,
+    *,
+    use_pallas: bool = False,
+) -> jax.Array:
+    """GP posterior mean at ``x``: (m, d) -> (m, p), one XLA program per m.
+
+    The trained state is an argument, never a closed-over constant: a
+    freshly fitted GP of the same shapes reuses the compiled programs
+    (and the persistent compile cache) instead of compiling new ones.
+
+    Row ``i`` of the result is bit-identical for every ``m`` (the
+    coalesced-dispatch guarantee; checked for the jnp kernel, not the
+    Pallas one): the kernel row comes from :func:`_matern52_rows`, and
+    ``ks @ alpha`` is an elementwise multiply and a fixed-order tree of
+    adds over n.  A GEMM there picks its blocking per m on a CPU, and on a
+    v5e a reduce over n takes its order from m's layout; the ulp either
+    costs is amplified by the cancelling sum.
+    """
+    x = jnp.atleast_2d(x)
+    if use_pallas:
+        ks = _kernel_fn(True)(x, x_train, params)  # (m, n)
+    else:
+        ks = _matern52_rows(x, x_train, params)
+    return _tree_sum(ks[:, :, None] * alpha[None, :, :]) * y_scale + y_mean
 
 
 NOISE_FLOOR = 1e-5  # keeps fp32 Cholesky well-conditioned on normalised y
@@ -93,20 +178,19 @@ class GaussianProcess:
     use_pallas: bool = False
 
     def predict(self, x: jax.Array, return_var: bool = False):
-        """Posterior mean (and variance) at x: (m, d) -> (m, p)."""
-        kfn = _kernel_fn(self.use_pallas)
-        ks = kfn(jnp.atleast_2d(x), self.x_train, self.params)  # (m, n)
-        # Elementwise multiply + fixed-order reduce instead of `ks @ alpha`:
-        # a GEMM picks different blocking per row count m, which costs an
-        # ulp between m = 1 and m = 8 — fatal for the coalesced-dispatch
-        # guarantee that batched results equal per-request results bit for
-        # bit.  The reduction order over n here is independent of m.
-        mean = (
-            jnp.sum(ks[:, :, None] * self.alpha[None, :, :], axis=1)
-            * self.y_scale + self.y_mean
+        """Posterior mean (and variance) at x: (m, d) -> (m, p).
+
+        The mean is one compiled program per m (:func:`posterior_mean`);
+        the variance, which no served level asks for, evaluates eagerly.
+        """
+        mean = posterior_mean(
+            x, self.x_train, self.alpha, self.y_scale, self.y_mean, self.params,
+            use_pallas=self.use_pallas,
         )
         if not return_var:
             return mean
+        x = jnp.atleast_2d(x)
+        ks = _kernel_fn(self.use_pallas)(x, self.x_train, self.params)  # (m, n)
         v = jax.scipy.linalg.solve_triangular(self.chol, ks.T, lower=True)
         kss = jnp.exp(self.params.log_outputscale)
         var = jnp.maximum(kss - jnp.sum(v * v, axis=0), 1e-12)
@@ -119,15 +203,16 @@ class GaussianProcess:
     def batch_call(self, thetas: jax.Array) -> jax.Array:
         """Batched posterior mean for a stacked ``(B, d)`` parameter array.
 
-        One ``(B, n)`` kernel assembly + one fixed-order contraction
-        (see :meth:`predict` — deliberately NOT a GEMM) answers the whole
-        coalesced batch — the :class:`repro.balancer.types.BatchServer`
-        handler for level 0.  Row ``i`` runs the same arithmetic as
-        ``__call__(thetas[i])`` regardless of ``B``, so members are
-        bit-identical (fp32) to per-request evaluation — verified in
-        ``tests/test_batch_dispatch.py``.
+        One launch of the compiled :func:`posterior_mean` program for this
+        ``B`` — a ``(B, n)`` kernel assembly + one fixed-order contraction
+        (deliberately NOT a GEMM) — answers the whole coalesced batch: the
+        :class:`repro.balancer.types.BatchServer` handler for level 0.
+        Row ``i`` runs the same arithmetic as ``__call__(thetas[i])``
+        regardless of ``B``, so members are bit-identical (fp32) to
+        per-request evaluation — verified in
+        ``tests/test_batch_dispatch.py`` on the CPU, and on a v5e.
         """
-        return self.predict(jnp.atleast_2d(thetas))
+        return self.predict(thetas)
 
 
 def fit_gp(

@@ -60,7 +60,8 @@ def make_level_servers(
     one fall back to per-request servers.  ``batch_forwards`` is
     ``(level0, level1, level2)`` stacked handlers — ``None`` entries fall
     back too.  The GP's own :meth:`~repro.core.gp.GaussianProcess.batch_call`
-    is used automatically when no explicit level-0 handler is given.
+    (one compiled program per batch size) is used automatically when no
+    explicit level-0 handler is given.
 
     When ``policy`` (a :class:`~repro.runtime.sharding.ShardingPolicy`) is
     also given, levels with a traceable stacked forward

@@ -427,6 +427,48 @@ def test_gp_batch_call_bit_identical():
     assert np.array_equal(want, got)
 
 
+@pytest.fixture(scope="module")
+def small_gp():
+    from repro.core.gp import fit_gp
+
+    rng = np.random.default_rng(1)
+    x = rng.uniform(-1, 1, (48, 2))
+    y = np.stack([np.sin(x[:, 0]), x[:, 0] * x[:, 1]], axis=1)
+    return fit_gp(x, y, steps=20), rng.uniform(-1, 1, (8, 2))
+
+
+def _eager_posterior_mean(gp, thetas):
+    """The level-0 mean as it evaluated before it was compiled: op by op."""
+    import jax.numpy as jnp
+    from repro.core.gp import matern52
+
+    ks = matern52(jnp.asarray(thetas), gp.x_train, gp.params)
+    terms = ks[:, :, None] * gp.alpha[None, :, :]
+    mean = jnp.sum(terms, axis=1) * gp.y_scale + gp.y_mean
+    # Sum of |terms|: the scale of the cancelling contraction, which sets
+    # how far a different rounding of the same formula can move the mean.
+    size = jnp.sum(jnp.abs(terms), axis=1) * gp.y_scale + jnp.abs(gp.y_mean)
+    return np.asarray(mean), np.asarray(size)
+
+
+@pytest.mark.parametrize("b", [1, 2, 3, 5, 8])
+def test_gp_batch_rows_match_per_row_and_eager_formula(small_gp, b):
+    """The compiled level-0 call: every row of a B-row call equals the
+    one-row call bit for bit, and equals the eager formula up to float32
+    rounding (the compiled program adds in a fixed tree order, and XLA
+    may contract a multiply-add where the eager ops rounded twice)."""
+    import jax.numpy as jnp
+
+    gp, thetas = small_gp
+    thetas = thetas[:b]
+    got = np.asarray(gp.batch_call(jnp.asarray(thetas)))
+    per_row = np.stack([np.asarray(gp(jnp.asarray(t))) for t in thetas])
+    assert got.shape == (b, 2) and got.dtype == np.float32
+    assert np.array_equal(got, per_row)
+    want, size = _eager_posterior_mean(gp, thetas)
+    assert np.all(np.abs(got - want) <= 16 * np.finfo(np.float32).eps * size)
+
+
 def test_batched_pallas_step_matches_reference(small_scenario):
     """Fused (no-transpose) and strip (batch grid axis) kernels vs the
     pure-jnp oracle, fp32 tolerance as in test_kernels."""

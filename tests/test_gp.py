@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import fit_gp, latin_hypercube, scale_to_bounds
-from repro.core.gp import GPParams, matern52
+from repro.core.gp import GPParams, matern52, posterior_mean
 
 
 def _func(x):
@@ -74,3 +74,36 @@ def test_matern_kernel_psd():
     k = np.asarray(matern52(x, x, p))
     eig = np.linalg.eigvalsh(k)
     assert eig.min() > -1e-4
+
+
+def _fit_small(seed):
+    x = latin_hypercube(jax.random.key(seed), 32, 2)
+    return fit_gp(x, _func(x), steps=10)
+
+
+def test_posterior_mean_compiles_once_per_batch_size():
+    gp = _fit_small(0)
+    thetas = latin_hypercube(jax.random.key(5), 4, 2)
+    first = gp.batch_call(thetas)
+    n = posterior_mean._cache_size()
+    again = gp.batch_call(thetas)
+    assert posterior_mean._cache_size() == n
+    assert np.array_equal(np.asarray(first), np.asarray(again))
+
+
+def test_refitted_gps_share_one_program_per_batch_size():
+    """The trained state is a jit argument, not a constant baked into the
+    program: a GP fitted on other data reuses the compiled programs."""
+    gp_a, gp_b = _fit_small(0), _fit_small(1)
+    assert not np.array_equal(np.asarray(gp_a.alpha), np.asarray(gp_b.alpha))
+    thetas = latin_hypercube(jax.random.key(6), 3, 2)  # (3, 2) x 32 points: new here
+    n = posterior_mean._cache_size()
+    out_a = gp_a.batch_call(thetas)
+    assert posterior_mean._cache_size() == n + 1
+    out_b = gp_b.batch_call(thetas)
+    assert posterior_mean._cache_size() == n + 1
+    assert not np.array_equal(np.asarray(out_a), np.asarray(out_b))
+    gp_b(thetas[0])
+    m = posterior_mean._cache_size()
+    gp_a(thetas[1])
+    assert posterior_mean._cache_size() == m
