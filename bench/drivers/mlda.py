@@ -176,10 +176,11 @@ class MLDACell:
         return [2] if self.resident else list(LEVELS)
 
     def warm_shapes(self) -> None:
-        """Compile every batch size a level's pool can be handed: the GP
-        evaluates eagerly at each size 1..max_batch, the PDE levels pad to
-        powers of two.  Device-resident chains also compile their fused
-        propose / accept programs."""
+        """Compile, or load from the compile cache, every batch size a
+        level's pool can be handed: the GP's posterior mean is one program
+        per size 1..max_batch, the PDE levels pad to powers of two.
+        Device-resident chains also compile their fused propose / accept
+        programs."""
         import jax.numpy as jnp
 
         mb = self.w.max_batch

@@ -6,7 +6,8 @@ with no growing backlog (run on the chip).
 
 One process sets the cell up once (weights from the first seed) and then
 runs one window per rate and seed, with the cell's traffic at that rate
-and that seed's schedule.  Per window it
+and that seed's prompt tokens (the order of the schedule is the same for
+every seed, ``bench/traffic.py``).  Per window it
 prints the tails, the drain the stragglers needed after the window, and
 how TTFT grew from the window's first half to its second: a backlog that
 grows shows as a long drain and a second half far slower than the first.
@@ -55,16 +56,16 @@ def main(argv=None) -> int:
         win = lmc.window(sched)
         ttft, gaps, failed, _ = lm.tally(sched, win)
         half = sched.due_s < args.seconds / 2
+        first, second = float(np.median(ttft[half])), float(np.median(ttft[~half]))
         print(json.dumps({
             "rate_per_s": rate,
             "seed": seed,
             "requests": len(sched),
             "failed": len(failed),
-            "ttft_ms_p50": float(np.median(ttft)) * 1e3,
-            "ttft_ms_p95": harness.quantile(ttft, 0.95) * 1e3,
-            "ttft_ms_p50_first_half": float(np.median(ttft[half])) * 1e3,
-            "ttft_ms_p50_second_half": float(np.median(ttft[~half])) * 1e3,
-            "itl_ms_p95": harness.quantile(gaps, 0.95) * 1e3 if len(gaps) else None,
+            **lm.tails(ttft, gaps),
+            "ttft_ms_p50_first_half": first * 1e3,
+            "ttft_ms_p50_second_half": second * 1e3,
+            "ttft_p50_second_over_first": second / first,
             "drain_s": win["t_end"] - win["t_close"],
             "slot_occupancy": windowed.occupancy(win["before"], win["after"]),
             "queue_wait_ms_mean": windowed.idle_mean_ms(win["before"], win["after"]),

@@ -48,6 +48,34 @@ def lm_traffic() -> dict:
     return tr
 
 
+def serving_metrics(cell_name: str) -> tuple:
+    """The serving cell's end-to-end and per-layer entries, as the PR that
+    brings the first serving cell adds them to BENCHMARK.json (bounds from
+    the chip, PERF.md section 2).  BENCHMARK.json lists no metric without a
+    cell that reports it, so they are not in the committed file yet."""
+    def e2e(name, bound):
+        return {"name": name, "unit": "ms", "better": "lower", "bound": bound,
+                "source": "host_clock", "workloads": [cell_name]}
+
+    def layer(name, unit, better, source, layer_name, moves):
+        return {"name": name, "unit": unit, "better": better, "source": source,
+                "layer": layer_name, "moves": moves, "workloads": [cell_name]}
+
+    return (
+        [e2e("ttft_ms_p50", 0.25), e2e("ttft_ms_p80", 0.25), e2e("itl_ms_p99", 0.08)],
+        [
+            layer("decode_step_ms.serve", "ms", "lower", "device_trace", "programs", "itl_ms_p99"),
+            layer("queue_wait_ms_mean.serve", "ms", "lower", "program_counter", "dispatcher",
+                  "ttft_ms_p80"),
+            layer("slot_occupancy.serve", "%", "higher", "program_counter", "dispatcher",
+                  "itl_ms_p99"),
+            layer("mfu.serve", "%", "higher", "device_trace", "whole step", "ttft_ms_p50"),
+            layer("device_idle_share.serve", "%", "lower", "device_trace", "device",
+                  "ttft_ms_p50"),
+        ],
+    )
+
+
 def cell(config: dict, traffic: dict, name: str = "small") -> harness.Cell:
     return harness.Cell(name, 1, config, traffic, [], [], BENCH)
 

@@ -29,10 +29,16 @@ def lm_fault(label, fn):
     return step
 
 
+SERVE_E2E = {"ttft_ms_p50", "ttft_ms_p80", "itl_ms_p99", "setup_s"}
+
+
 def lm_cell():
+    """The small chat cell, reporting the serving metrics."""
     cfg = small.lm_config()
     cfg["weights"]["embed_std"] = 1.0  # logits far apart: a wrong token shows
-    return small.cell(cfg, small.lm_traffic())
+    c = small.cell(cfg, small.lm_traffic())
+    c.end_to_end = small.serving_metrics(c.name)[0] + [{"name": "setup_s", "unit": "s"}]
+    return c
 
 
 @pytest.mark.parametrize("resident", [False, True])
@@ -50,3 +56,5 @@ def test_lm_run(broken):
     line = run_cell(small.context(lm_cell(), seconds=2.0, tamper=lm_fault if broken else None))
     assert line["correct"] is (not broken)
     assert line["failed"] == 0 and line["attempted"] > 0
+    assert set(line["metrics"]) == SERVE_E2E
+    assert all(0 < m["value"] < float("inf") for m in line["metrics"].values())

@@ -24,12 +24,31 @@ def test_open_loop_seeds_share_the_work():
     a = gen.open_loop(tr, 151936, 1, 10.0)
     b = gen.open_loop(tr, 151936, BIG_SEED, 10.0)
     assert len(a) == len(b) == int(tr["rate_per_s"] * 10.0)
-    assert not np.array_equal(a.prompt_len, b.prompt_len)  # another order ...
-    assert np.array_equal(np.sort(a.prompt_len), np.sort(b.prompt_len))  # ... same sizes
-    assert np.array_equal(np.sort(a.new_tokens), np.sort(b.new_tokens))
+    # the same requests at the same times ...
+    assert np.array_equal(a.due_s, b.due_s)
+    assert np.array_equal(a.prompt_len, b.prompt_len)
+    assert np.array_equal(a.new_tokens, b.new_tokens)
+    # ... with other token ids
+    assert not any(np.array_equal(x, y) for x, y in zip(a.prompts, b.prompts))
+    assert 0.0 < a.due_s[0] and a.due_s[-1] < 10.0
+
+
+@pytest.mark.parametrize("seconds", [10.0, 51.0])
+def test_open_loop_order_is_fixed_not_seeded(seconds, monkeypatch):
+    tr = small.load("traffic", "chat-steady")
+    a = gen.open_loop(tr, 151936, BIG_SEED, seconds)
+    n = len(a)
+    sizes = gen.lognormal_sizes(tr["prompt"], n)
+    assert np.array_equal(np.sort(a.prompt_len), np.sort(sizes))
+    assert not np.array_equal(a.prompt_len, np.sort(a.prompt_len))  # shuffled
+    monkeypatch.setattr(gen, "ORDER", gen.ORDER + 1)
+    other = gen.open_loop(tr, 151936, BIG_SEED, seconds)
+    assert not np.array_equal(a.prompt_len, other.prompt_len)  # another order ...
+    assert np.array_equal(np.sort(a.prompt_len), np.sort(other.prompt_len))  # ... same sizes
+    assert np.array_equal(np.sort(a.new_tokens), np.sort(other.new_tokens))
     gaps = lambda s: np.sort(np.diff(np.concatenate([[0.0], s.due_s])))  # noqa: E731
-    assert gaps(a) == pytest.approx(gaps(b))
-    assert a.due_s[-1] == pytest.approx(b.due_s[-1]) and 0.0 < a.due_s[0] and a.due_s[-1] < 10.0
+    assert gaps(a) == pytest.approx(gaps(other))
+    assert a.due_s[-1] == pytest.approx(other.due_s[-1]) and a.due_s[-1] < seconds
 
 
 def test_open_loop_sizes_follow_the_mix():

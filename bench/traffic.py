@@ -11,12 +11,14 @@ parameters.  Two kinds exist:
   requests, each with a due time, a prompt length, an output length and
   prompt token ids.
 
-Every seed of an ``open_loop`` mix gets the same multiset of inter-arrival
-gaps, prompt lengths and output lengths, in another order: the sizes are
-the distributions' quantiles at ``(i + 0.5) / n``, shuffled by the seed.
-Only the order and the token ids change with the seed, so two seeds ask
-for the same total work at the same mean rate, and a run-to-run difference
-comes from the system, not from a heavier draw.
+Every seed of an ``open_loop`` mix gets the same schedule: the
+inter-arrival gaps, prompt lengths and output lengths are the
+distributions' quantiles at ``(i + 0.5) / n``, put in one fixed order
+(:data:`ORDER`).  The seed draws only the prompt token ids (and, in the
+driver, the weights), so two seeds ask for the same work at the same
+times, and a run-to-run difference comes from the system.  Under queueing
+the order alone moves the latency tails by more than a bound can hold: a
+seed that shuffled the order changed the work.
 """
 from __future__ import annotations
 
@@ -91,6 +93,10 @@ def exponential_gaps(rate_per_s: float, n: int) -> np.ndarray:
     return -np.log1p(-_quantiles(n)) / float(rate_per_s)
 
 
+# The one order of every open-loop schedule's gaps and sizes.
+ORDER = 0
+
+
 def open_loop(params: Dict[str, Any], vocab: int, seed: int, seconds: float) -> Schedule:
     if params.get("arrivals", "poisson") != "poisson":
         raise ValueError(f"unknown arrival process {params.get('arrivals')!r}")
@@ -98,10 +104,11 @@ def open_loop(params: Dict[str, Any], vocab: int, seed: int, seconds: float) -> 
     n = int(math.floor(rate * seconds))
     if n < 1:
         raise ValueError(f"rate {rate}/s over {seconds} s gives no request")
-    gaps_ss, prompt_ss, out_ss, tok_ss = seed_words(seed).spawn(4)
+    gaps_ss, prompt_ss, out_ss, _ = seed_words(ORDER).spawn(4)
+    tok_ss = seed_words(seed).spawn(4)[3]
     gaps = np.random.default_rng(gaps_ss).permutation(exponential_gaps(rate, n))
-    # Request i is due after i + 1 gaps; the multiset of gaps (and so the
-    # last due time, just inside the window) is the same for every seed.
+    # Request i is due after i + 1 gaps; the sum of the gaps (the last due
+    # time) lies just inside the window.
     due = np.cumsum(gaps)
     prompt_len = np.random.default_rng(prompt_ss).permutation(
         lognormal_sizes(params["prompt"], n)
