@@ -111,6 +111,13 @@ def make_weights(cfg: Dict[str, Any], seed31: int):
     return params
 
 
+# The whole warm-up's budget: 4.9 x the 18.53 s cold warm-up of the 17
+# programs of qwen2-0.5b's pool with the weights as jit arguments, on a
+# TPU v5e.  A pool that closes the weights into each program compiles each
+# for about 43 s and ran a 40 GiB host out of memory at the sixth.
+WARM_BUDGET_S = 90.0
+
+
 def paged_pool(cfg: Dict[str, Any], arch, params, name: str, tag: str):
     """The program's paged decode pool,
     :func:`repro.runtime.serve_loop.make_paged_decode_pool`, over the
@@ -164,16 +171,42 @@ class LMCell:
 
         return Generation(self.lb, self.variant, (prompt.astype(np.int64), int(n_new), None), "paged")
 
-    def warm(self) -> None:
+    def warm(self) -> str:
         """Prefill chunks of every length 1..prefill_chunk and the decode
-        step, through the served path."""
+        step, through the served path, within :data:`WARM_BUDGET_S`;
+        returns a note on what it took.
+
+        A pool whose programs compile far slower than that (one that takes
+        the weights into each program as constants compiles for tens of
+        seconds and some GB of host memory apiece) ends the run here with a
+        :class:`~bench.harness.BenchError`, before the host runs out of
+        memory."""
+        budget = WARM_BUDGET_S
         rng = np.random.default_rng(0)
         vocab = self.cfg["vocab_size"]
+        t0, (n0, s0) = now(), self.ctx.compile_clock.snapshot()
+
+        def compiled():
+            n, s = self.ctx.compile_clock.snapshot()
+            return n - n0, s - s0
+
         gens = [
             self.submit(rng.integers(0, vocab, size=k), 2) for k in range(1, self.chunk + 1)
         ]
-        for g in gens:
-            g.result(timeout=900)
+        for done, g in enumerate(gens):
+            try:
+                g.result(timeout=max(0.0, t0 + budget - now()))
+            except TimeoutError:
+                n, s = compiled()
+                each = f" ({s / n:.1f} s each)" if n else ""
+                raise harness.BenchError(
+                    f"the pool's warm-up did not finish within WARM_BUDGET_S = {budget:g} s: "
+                    f"{done} of {len(gens)} warm-up requests done, {n} programs compiled in "
+                    f"{s:.1f} s{each}. Programs that take the weights as compile-time "
+                    "constants compile this slowly; pass the weights as arguments"
+                ) from None
+        n, s = compiled()
+        return f"[warm] {now() - t0:.3f} s (budget {budget:g} s), {n} compiles ({s:.3f} s)"
 
     def window(self, sched: gen.Schedule) -> Dict[str, Any]:
         ctx = self.ctx
@@ -334,7 +367,7 @@ def run(ctx: harness.Context) -> Outcome:
 
     cfg = ctx.cell.config
     cell = LMCell(ctx)
-    cell.warm()
+    warm_note = cell.warm()
     sched = gen.open_loop(cell.tr, cfg["vocab_size"], ctx.seed, ctx.seconds)
     win = cell.window(sched)
     stats = jax.devices()[0].memory_stats() or {}
@@ -363,6 +396,7 @@ def run(ctx: harness.Context) -> Outcome:
     window_s = win["t_close"] - win["t0"]
     notes = [
         f"[setup] {win['t0'] - ctx.process_start:.3f} s to the window",
+        warm_note,
         f"[generator] lateness over {len(late)} requests: p50 {np.median(late) * 1e3:.3f} ms, "
         f"p95 {quantile(late, 0.95) * 1e3:.3f} ms, max {late.max() * 1e3:.3f} ms",
         f"[window] {window_s:.3f} s, {len(sched)} requests due, {len(failed)} failed, "

@@ -4,6 +4,7 @@ skipped; everything else is the benchmark's own run."""
 import numpy as np
 import pytest
 
+from bench import harness
 from bench.run import run_cell
 from bench.tests import small
 
@@ -58,3 +59,16 @@ def test_lm_run(broken):
     assert line["failed"] == 0 and line["attempted"] > 0
     assert set(line["metrics"]) == SERVE_E2E
     assert all(0 < m["value"] < float("inf") for m in line["metrics"].values())
+
+
+def test_lm_warm_budget_ends_the_run(monkeypatch):
+    """A pool that has not warmed within the warm-up budget ends the run
+    with an error that names the budget."""
+    lm = harness.driver(small.BENCH, "lm")
+    monkeypatch.setattr(lm, "WARM_BUDGET_S", 0.0)
+    cell = lm.LMCell(small.context(lm_cell()))
+    try:
+        with pytest.raises(harness.BenchError, match="WARM_BUDGET_S = 0 s: 0 of 4"):
+            cell.warm()
+    finally:
+        cell.shutdown()
